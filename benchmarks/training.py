@@ -17,7 +17,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.data import SyntheticLMDataset
 from repro.distributed.steps import init_train_state, make_train_fn
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 
 
@@ -26,7 +26,7 @@ def bench_train_step() -> list[tuple[str, float, str]]:
     B, T = 8, 128
     mesh = make_local_mesh()
     data = SyntheticLMDataset(B, T, cfg.vocab_size)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, _ = make_train_fn(cfg, mesh, "fsdp_tp",
                               shape=ShapeConfig("b", T, B, "train"))
         state = init_train_state(cfg, jax.random.PRNGKey(0))

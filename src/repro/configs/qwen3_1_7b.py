@@ -1,6 +1,6 @@
 """Qwen3-1.7B — dense GQA with per-head QK RMSNorm.
 
-[hf:Qwen/Qwen3-8B family] 28L d_model=2048 16H (GQA kv=8) d_ff=6144
+[hf:Qwen/Qwen3-1.7B] 28L d_model=2048 16H (GQA kv=8) d_ff=6144
 vocab=151936, head_dim 128, qk_norm.
 """
 from repro.configs.base import ModelConfig
@@ -22,5 +22,5 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     decode_window=8192,
     supports_long_context=True,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
 )

@@ -1,14 +1,16 @@
 """Flash attention Pallas TPU kernel: online-softmax tiling with GQA, causal
 and sliding-window masking.
 
-TPU adaptation (DESIGN.md §6): the GPU algorithm's warp-level softmax turns
+TPU adaptation: the GPU algorithm's warp-level softmax turns
 into MXU-aligned (block_q x block_k) tiles streamed HBM->VMEM; the running
 (m, l, acc) state lives in VMEM scratch and persists across the sequential
 innermost grid dimension (TPU grids iterate in order, which replaces the GPU
 thread-block loop).
 
-Grid: (B, KV_heads, num_q_blocks, num_k_blocks), k innermost.
-Blocks: q (1, bq, 1, G, hd) | k,v (1, bk, 1, hd) | o (1, bq, 1, G, hd).
+Grid: (B, H, num_q_blocks, num_k_blocks), k innermost. Operands are taken
+head-major, so each block ends in (block, hd) as the chip's (8, 128) tiling
+rule wants; query head h reads kv head h // (H // KV) (GQA).
+Blocks: q, o (1, 1, bq, hd) | k, v (1, 1, bk, hd).
 """
 from __future__ import annotations
 
@@ -36,31 +38,32 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :, :].astype(jnp.float32)     # (bq, G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)        # (bk, hd)
+    q = q_ref[0, 0, :, :].astype(jnp.float32)        # (bq, hd)
+    k = k_ref[0, 0, :, :].astype(jnp.float32)        # (bk, hd)
+    v = v_ref[0, 0, :, :].astype(jnp.float32)        # (bk, hd)
 
-    s = jnp.einsum("qgh,kh->qgk", q, k) * scale      # (bq, G, bk)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
 
-    qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1, 1), 0)
-    kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_k), 2)
-    mask = jnp.ones((block_q, 1, block_k), jnp.bool_)
+    qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    mask = jnp.ones((block_q, block_k), jnp.bool_)
     if causal:
         mask &= kpos <= qpos
     if window > 0:
         mask &= kpos > qpos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]                              # (bq, G)
+    m_prev = m_scr[...]                              # (bq, 1)
     l_prev = l_scr[...]
-    m_cur = jnp.max(s, axis=-1)
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     # guard fully-masked running max
-    p = jnp.exp(s - m_new[..., None])
+    p = jnp.exp(s - m_new)
     p = jnp.where(mask, p, 0.0)
     alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-    acc = acc_scr[...] * alpha[..., None] + jnp.einsum("qgk,kh->qgh", p, v)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc_scr[...] * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
 
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -70,9 +73,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = l_scr[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = acc_scr[...] / safe_l[..., None]
-        out = jnp.where((l == 0.0)[..., None], 0.0, out)
-        o_ref[0, :, 0, :, :] = out.astype(o_ref.dtype)
+        out = acc_scr[...] / safe_l
+        out = jnp.where(l == 0.0, 0.0, out)
+        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -123,7 +126,10 @@ def _flash_impl(q, k, v, *, causal: bool, window: int, block_q: int,
     bk = min(block_k, S)
     assert T % bq == 0 and S % bk == 0, (T, bq, S, bk)
     nq, nk = T // bq, S // bk
-    q5 = q.reshape(B, T, KV, G, hd)
+    # head-major, so every block ends in (block, hd): the chip's tiling rule
+    qh = q.transpose(0, 2, 1, 3)                     # (B, H, T, hd)
+    kh = k.transpose(0, 2, 1, 3)                     # (B, KV, S, hd)
+    vh = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _flash_kernel, scale=1.0 / (hd ** 0.5), causal=causal, window=window,
@@ -131,19 +137,19 @@ def _flash_impl(q, k, v, *, causal: bool, window: int, block_q: int,
 
     out = pl.pallas_call(
         kernel,
-        grid=(B, KV, nq, nk),
+        grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, G, hd), lambda b, h, i, j: (b, i, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, j: (b, j, h, 0)),
+            pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, G, hd), lambda b, h, i, j: (b, i, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, KV, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, G), jnp.float32),        # running max m
-            pltpu.VMEM((bq, G), jnp.float32),        # running denom l
-            pltpu.VMEM((bq, G, hd), jnp.float32),    # accumulator
+            pltpu.VMEM((bq, 1), jnp.float32),        # running max m
+            pltpu.VMEM((bq, 1), jnp.float32),        # running denom l
+            pltpu.VMEM((bq, hd), jnp.float32),       # accumulator
         ],
         interpret=interpret,
-    )(q5, k, v)
-    return out.reshape(B, T, H, hd)
+    )(qh, kh, vh)
+    return out.transpose(0, 2, 1, 3)

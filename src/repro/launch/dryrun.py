@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from repro.configs import INPUT_SHAPES, get_config
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed import steps as S
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 
 from repro.launch.dryrun_lib import (  # noqa: E402
     COLLECTIVE_OPS,
@@ -47,7 +47,7 @@ from repro.launch.dryrun_lib import (  # noqa: E402
 
 def _lower_one(cfg: ModelConfig, shape: ShapeConfig, mesh, strategy: str):
     """Lower + compile one step; returns (compiled, lowered)."""
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             fn, _ = S.make_train_fn(cfg, mesh, strategy, shape=shape)
             lowered = fn.lower(S.abstract_train_state(cfg),

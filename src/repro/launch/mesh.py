@@ -4,49 +4,17 @@ A function (not a module-level constant) so importing this module never
 touches jax device state. The single-pod production mesh is 16x16 = 256
 chips (one TPU v5e pod); multi-pod is 2x16x16 = 512 chips with a leading
 'pod' axis (DCN boundary).
-
-JAX version compatibility: ``jax.sharding.AxisType`` / the ``axis_types=``
-kwarg of ``jax.make_mesh`` and the ambient-mesh context ``jax.set_mesh``
-only exist on newer JAX releases. ``make_mesh_compat`` / ``set_mesh`` below
-use them when present and degrade gracefully otherwise (all sharding in this
-repo is explicit ``NamedSharding``, so the ambient mesh is advisory) — the
-supported floor is the installed JAX (0.4.x).
 """
 from __future__ import annotations
 
-from typing import Sequence
-
 import jax
-
-
-def make_mesh_compat(shape: Sequence[int],
-                     axes: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across JAX versions: request explicit Auto axis
-    types where the API supports them, plain mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes),
-                axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` on new JAX,
-    the Mesh's own context manager on older releases."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model_parallel: int | None = None) -> jax.sharding.Mesh:
@@ -58,8 +26,9 @@ def make_local_mesh(model_parallel: int | None = None) -> jax.sharding.Mesh:
             if n % m == 0:
                 model_parallel = m
                 break
-    return make_mesh_compat((n // model_parallel, model_parallel),
-                            ("data", "model"))
+    return jax.make_mesh((n // model_parallel, model_parallel),
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 # Hardware constants (TPU v5e target) used by the roofline analysis.

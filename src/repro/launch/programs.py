@@ -12,6 +12,7 @@ same program.
 """
 from __future__ import annotations
 
+import functools
 import json
 import time
 from typing import Callable
@@ -19,14 +20,15 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.checkpoint import AsyncCheckpointer, Checkpointer, tree_nbytes
 from repro.configs.base import ModelConfig
 from repro.core.cluster_spec import spec_task_counts
 from repro.core.task_executor import JobContext
 from repro.data import PrefetchingLoader, make_dataset
+from repro.distributed.sharding import to_shardings
 from repro.distributed.steps import init_train_state, make_train_fn
-from repro.launch.mesh import make_mesh_compat, set_mesh
 from repro.optim import AdamWConfig
 
 
@@ -39,7 +41,8 @@ def _local_mesh(strategy: str):
         if n % m == 0 and m <= n:
             model = m
             break
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
@@ -160,25 +163,33 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
             ctx.register_flusher(ckpt.flush)
         else:
             ckpt = Checkpointer(ckpt_dir)
-        with set_mesh(mesh):
-            train_fn, _ = make_train_fn(
+        with jax.set_mesh(mesh):
+            train_fn, state_pspecs = make_train_fn(
                 cfg, mesh, strategy, opt=AdamWConfig(lr=lr, weight_decay=0.0))
-            state = init_train_state(cfg, jax.random.PRNGKey(0))
+            # the state is born sharded: never gathered whole on one device
+            shardings = to_shardings(state_pspecs, mesh)
+            init = jax.jit(functools.partial(init_train_state, cfg),
+                           out_shardings=shardings)
+            rng = jax.random.PRNGKey(0)
             # checkpoint-aware recovery: prefer the AM's resume_step (the
             # deepest checkpoint a previous attempt committed), fall back to
             # whatever this directory holds (resume across submissions), and
             # only then cold-start from step 0
             start = 0
+            state = None
+            template = jax.eval_shape(init, rng)
             target = ctx.shared.get("resume_step")
             if target is None:
                 target = ckpt.latest_step()
             if target is not None:
                 try:
-                    state = ckpt.restore(state, int(target))
+                    state = ckpt.restore(template, int(target))
                 except (FileNotFoundError, KeyError, ValueError, OSError):
                     target = ckpt.latest_step()
                     if target is not None:
-                        state = ckpt.restore(state, int(target))
+                        state = ckpt.restore(template, int(target))
+            state = (init(rng) if state is None
+                     else jax.device_put(state, shardings))
             if target is not None:
                 data.load_state_dict({"step": int(target)})
                 start = int(target)

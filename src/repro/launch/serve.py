@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import TonYClient, YarnLikeBackend, job_spec_from_props, make_cluster
-from repro.launch.mesh import make_local_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
@@ -75,14 +75,16 @@ def make_serve_program(cfg, *, batch: int, prompt_len: int, gen_len: int,
     return program
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> int:
+    """Runs one job; returns the exit code (0 only when it SUCCEEDED)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--gen", type=int, default=16)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cache_len = args.prompt_len + args.gen
@@ -104,7 +106,8 @@ def main() -> None:
                       "stats": box.get("stats"),
                       "sample_tokens": box["gen"][0][:8].tolist()
                       if "gen" in box else None}, indent=2))
+    return 0 if result.succeeded else 1
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

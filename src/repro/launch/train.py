@@ -28,6 +28,7 @@ from repro.core import (
     job_spec_from_props,
     make_cluster,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.programs import make_train_program
 
 
@@ -54,7 +55,8 @@ def build_job(name: str, workers: int, ps: int, gpus_per_worker: int = 1,
     return job_spec_from_props(props)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> int:
+    """Runs one job; returns the exit code (0 only when it SUCCEEDED)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tony-paper-mlp", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true",
@@ -131,7 +133,8 @@ def main() -> None:
                       help="consecutive lagging observations before a backup")
     spec.add_argument("--speculation-min-progress", type=int, default=4,
                       help="gang median step before detection arms")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="tony-train-")
@@ -212,7 +215,9 @@ def main() -> None:
     }, indent=2))
     if not result.succeeded:
         print(format_failure_report(result))
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -8,7 +8,7 @@ import pytest
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.distributed.steps import init_train_state, make_train_fn
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 
 EXPECTED = {
@@ -76,7 +76,7 @@ def test_smoke_one_train_step(arch, rng):
     cfg = get_smoke_config(arch)
     mesh = make_local_mesh()
     shape = ShapeConfig("smoke", 16, 2, "train")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, _ = make_train_fn(cfg, mesh, "fsdp_tp", shape=shape)
         state = init_train_state(cfg, rng)
         step0 = int(state["step"])

@@ -1,13 +1,21 @@
 """End-to-end system tests: real JAX training jobs through the full TonY path
 (client -> RM -> AM -> executors -> train loop), including checkpoint-restore
 fault tolerance — the paper's §2.2/§3 behaviour."""
+import importlib.util
 import os
+import subprocess
+import sys
+from pathlib import Path
 
+import jax
 import pytest
 
 from repro.configs import get_config
 from repro.core import TonYClient, YarnLikeBackend, job_spec_from_props, make_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.programs import make_train_program
+
+ROOT = Path(__file__).resolve().parents[1]
 
 CFG = get_config("tony-paper-mlp").replace(
     num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, head_dim=32,
@@ -89,3 +97,47 @@ def test_e2e_new_cluster_spec_each_attempt(tmp_path):
     s2 = res.attempts[1].cluster_spec
     assert s1 is not None and s2 is not None
     assert s1 != s2  # fresh ports/containers -> new global spec (paper §2.2)
+
+
+@pytest.mark.parametrize("faults,rc,status", [(0, 0, "SUCCEEDED"),
+                                              (5, 1, "FAILED")])
+def test_train_cli_exit_code_follows_job_status(tmp_path, faults, rc, status):
+    """The training CLI exits 0 only when its job SUCCEEDED: five seeded
+    faults, all at step 1, outlast the default three attempts. Its compile
+    cache goes where JAX_COMPILATION_CACHE_DIR says."""
+    cache = tmp_path / "jax_cache"
+    env = {**os.environ,
+           "PYTHONPATH": os.pathsep.join(
+               [str(ROOT / "src"), os.environ.get("PYTHONPATH", "")]),
+           "JAX_COMPILATION_CACHE_DIR": str(cache),
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--smoke", "--steps", "2",
+         "--workers", "1", "--ps", "0", "--batch-size", "2", "--seq-len", "16",
+         "--ckpt-dir", str(tmp_path / "ck"),
+         "--chaos-random-faults", str(faults)],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=240)
+    assert proc.returncode == rc, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert f'"status": "{status}"' in proc.stdout
+    assert any(cache.iterdir())
+
+
+def test_compile_cache_defaults_to_repo_root(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_smoke_refuses_without_a_tpu(capsys):
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    assert chip_smoke.main([]) == 1
+    out = capsys.readouterr()
+    assert "no TPU found" in out.err and '"ok"' not in out.out
