@@ -35,6 +35,8 @@ from typing import Callable
 import jax
 import numpy as np
 
+from repro.core import tracing
+
 _SEP = "|"
 _STEP_DIR = re.compile(r"step_(\d{8})")
 _LEGACY_FILE = re.compile(r"ckpt_(\d{8})\.npz")
@@ -47,19 +49,24 @@ ARRAYS_FILE = "arrays.npz"
 
 
 def _flatten(tree) -> dict[str, np.ndarray]:
+    """The host snapshot a checkpoint writes: path-keyed host arrays."""
     items = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
         items.append((key, leaf))
-    # start every device->host transfer before materializing any of them, so
-    # the copies overlap instead of serializing one blocking d2h at a time
-    for _, leaf in items:
-        if hasattr(leaf, "copy_to_host_async"):
-            try:
-                leaf.copy_to_host_async()
-            except Exception:  # noqa: BLE001 - committed buffers still readable
-                pass
-    return {key: np.asarray(leaf) for key, leaf in items}
+    with tracing.span("ckpt.snapshot") as snapshot:
+        # start every device->host transfer before materializing any of
+        # them, so the copies overlap instead of serializing one blocking
+        # d2h at a time
+        for _, leaf in items:
+            if hasattr(leaf, "copy_to_host_async"):
+                try:
+                    leaf.copy_to_host_async()
+                except Exception:  # noqa: BLE001 - committed buffers still readable
+                    pass
+        flat = {key: np.asarray(leaf) for key, leaf in items}
+        snapshot.attrs["bytes"] = sum(a.nbytes for a in flat.values())
+        return flat
 
 
 def tree_nbytes(tree) -> int:
@@ -109,8 +116,13 @@ def save_pytree(tree, directory: str, step: int,
     staged and before the COMMIT marker is written — the writer-window kill
     point.
     """
+    return _write(_flatten(tree), directory, step, pre_commit)
+
+
+def _write(flat: dict[str, np.ndarray], directory: str, step: int,
+           pre_commit: Callable[[], None] | None = None) -> str:
+    """``save_pytree``'s write of a host snapshot (see there)."""
     os.makedirs(directory, exist_ok=True)
-    flat = _flatten(tree)
     final = step_dir(directory, step)
     tmp = tempfile.mkdtemp(dir=directory, prefix=f".tmp-step_{step:08d}-")
     try:
@@ -183,19 +195,21 @@ def restore_pytree(template, directory: str, step: int | None = None):
         else:
             raise FileNotFoundError(
                 f"no committed checkpoint for step {step} in {directory}")
-    with np.load(path) as data:
-        flat = dict(data)
-    keys = []
-    for p, leaf in jax.tree_util.tree_flatten_with_path(template)[0]:
-        key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k))) for k in p)
-        if key not in flat:
-            raise KeyError(f"checkpoint missing {key}")
-        if tuple(flat[key].shape) != tuple(leaf.shape):
-            raise ValueError(f"shape mismatch for {key}: "
-                             f"{flat[key].shape} vs {leaf.shape}")
-        keys.append(flat[key])
-    treedef = jax.tree_util.tree_structure(template)
-    return jax.tree_util.tree_unflatten(treedef, keys)
+    with tracing.span("ckpt.restore.read"):
+        with np.load(path) as data:
+            flat = dict(data)
+        keys = []
+        for p, leaf in jax.tree_util.tree_flatten_with_path(template)[0]:
+            key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k)))
+                            for k in p)
+            if key not in flat:
+                raise KeyError(f"checkpoint missing {key}")
+            if tuple(flat[key].shape) != tuple(leaf.shape):
+                raise ValueError(f"shape mismatch for {key}: "
+                                 f"{flat[key].shape} vs {leaf.shape}")
+            keys.append(flat[key])
+        treedef = jax.tree_util.tree_structure(template)
+        return jax.tree_util.tree_unflatten(treedef, keys)
 
 
 class Checkpointer:
@@ -286,7 +300,7 @@ class AsyncCheckpointer(Checkpointer):
         """Snapshot now, write in the background. Blocks only while a
         previous write is still in flight (depth-1 backpressure)."""
         flat = _flatten(tree)          # host snapshot; safe to mutate tree after
-        with self._cond:
+        with tracing.span("ckpt.handoff"), self._cond:
             self._raise_pending_locked()
             while self._slot is not None or self._busy:
                 self._cond.wait(0.05)
@@ -333,7 +347,7 @@ class AsyncCheckpointer(Checkpointer):
             try:
                 t0 = time.monotonic()
                 pre = (lambda: self.chaos_hook(step)) if self.chaos_hook else None
-                path = save_pytree(flat, self.directory, step, pre_commit=pre)
+                path = _write(flat, self.directory, step, pre_commit=pre)
                 self._gc()
                 if self.on_commit is not None:
                     self.on_commit(step, path, time.monotonic() - t0,

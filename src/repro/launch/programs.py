@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import functools
 import json
+import queue
+import threading
 import time
 from typing import Callable
 
@@ -24,6 +26,7 @@ from jax.sharding import AxisType
 
 from repro.checkpoint import AsyncCheckpointer, Checkpointer, tree_nbytes
 from repro.configs.base import ModelConfig
+from repro.core import tracing
 from repro.core.cluster_spec import spec_task_counts
 from repro.core.task_executor import JobContext
 from repro.data import PrefetchingLoader, make_dataset
@@ -43,6 +46,32 @@ def _local_mesh(strategy: str):
             break
     return jax.make_mesh((n // model, model), ("data", "model"),
                          axis_types=(AxisType.Auto, AxisType.Auto))
+
+
+def _put_restored(state, shardings, **attrs):
+    """``jax.device_put`` of a restored host state onto its shardings.
+
+    Returns once the transfer is issued, as a plain ``device_put`` does, so
+    that the first step's retrace and executable load overlap the transfer.
+    The ``ckpt.restore.put`` span runs on a watcher thread from the call
+    until the bytes are on the device."""
+    issued: queue.Queue = queue.Queue(maxsize=1)
+
+    def put() -> None:
+        with tracing.span("ckpt.restore.put", **attrs):
+            try:
+                arrays = jax.device_put(state, shardings)
+            except Exception as e:  # noqa: BLE001 - re-raised by the caller
+                issued.put(e)
+                return
+            issued.put(arrays)
+            jax.block_until_ready(arrays)
+
+    threading.Thread(target=put, name="ckpt-restore-put", daemon=True).start()
+    arrays = issued.get()
+    if isinstance(arrays, Exception):
+        raise arrays
+    return arrays
 
 
 def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
@@ -69,6 +98,7 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
     training and resume behavior."""
 
     def program(env: dict[str, str], ctx: JobContext) -> int:
+        t_entry = time.monotonic()
         task_type = env["TASK_TYPE"]
         index = int(env["TASK_INDEX"])
         task_id = f"{task_type}:{index}"
@@ -82,10 +112,13 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
 
         # identify ourselves to the barrier so a chaos PARTITION window
         # blocks this endpoint's rendezvous (it can't reach its peers)
-        if not speculative and not ctx.rendezvous(timeout=60.0,
-                                                  exec_id=exec_id,
-                                                  attempt=attempt):
-            return 3  # cancelled before the job formed
+        if not speculative:
+            with tracing.span("task.rendezvous", exec_id=exec_id,
+                              attempt=attempt):
+                joined = ctx.rendezvous(timeout=60.0, exec_id=exec_id,
+                                        attempt=attempt)
+            if not joined:
+                return 3  # cancelled before the job formed
 
         worker_types = [t for t in ("worker", "chief") if t in spec]
         chief_type = worker_types[0] if worker_types else sorted(spec)[0]
@@ -93,7 +126,7 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
 
         rc = 0
         if is_chief:
-            rc = _chief_train_loop(env, ctx, attempt, exec_id)
+            rc = _chief_train_loop(env, ctx, attempt, exec_id, t_entry)
         else:
             # non-chief: stay alive for the duration of the job ("the ML
             # framework's distributed protocol" is collapsed into-process),
@@ -110,86 +143,89 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                     ctx.step(exec_id, attempt, my_step)
                 else:
                     time.sleep(0.002)
-            ctx.shared[f"metrics:{exec_id}"] = {
-                "peak_memory_mb": 64.0, "role": 0.0}
         if not speculative:
             ctx.shared["train_done"] = True
             ctx.rendezvous(timeout=30.0, exec_id=exec_id, attempt=attempt)
         return rc
 
-    def _chief_train_loop(env, ctx: JobContext, attempt: int, exec_id: str) -> int:
-        mesh = _local_mesh(strategy)
-        t_start = time.monotonic()
-        # elastic resize: shard for the gang that ACTUALLY launched, not the
-        # one the config asked for. A degraded attempt scales the global
-        # batch down proportionally (rounded to a multiple of the mesh's
-        # data axis so sharding stays valid); a full-size attempt keeps the
-        # configured batch byte-for-byte.
-        spec = json.loads(env["CLUSTER_SPEC"])
-        counts = spec_task_counts(spec)
-        targets = ctx.shared.get("target_counts") or {}
-        my_type = env["TASK_TYPE"]
-        n_actual = counts.get(my_type, 1)
-        n_target = targets.get(my_type, n_actual)
-        global_batch = batch_size
-        if 0 < n_actual < n_target:
-            data_ax = int(mesh.shape["data"])
-            scaled = max(1, batch_size * n_actual // n_target)
-            global_batch = max(data_ax, (scaled // data_ax) * data_ax)
-        data = make_dataset(data_kind, global_batch, seq_len, cfg.vocab_size,
-                            path=data_path, seed=data_seed)
-        if prefetch_depth > 0:
-            data = PrefetchingLoader(data, depth=prefetch_depth)
+    def _chief_train_loop(env, ctx: JobContext, attempt: int, exec_id: str,
+                          t_entry: float) -> int:
+        # the spans of one attempt tile the chief's timeline from entry to
+        # each step: task.rendezvous, chief.build (with ckpt.restore.read
+        # inside it on a resume), then one train.step each
+        with tracing.span("chief.build", exec_id=exec_id, attempt=attempt):
+            mesh = _local_mesh(strategy)
+            # elastic resize: shard for the gang that ACTUALLY launched, not
+            # the one the config asked for. A degraded attempt scales the
+            # global batch down proportionally (rounded to a multiple of the
+            # mesh's data axis so sharding stays valid); a full-size attempt
+            # keeps the configured batch byte-for-byte.
+            spec = json.loads(env["CLUSTER_SPEC"])
+            counts = spec_task_counts(spec)
+            targets = ctx.shared.get("target_counts") or {}
+            my_type = env["TASK_TYPE"]
+            n_actual = counts.get(my_type, 1)
+            n_target = targets.get(my_type, n_actual)
+            global_batch = batch_size
+            if 0 < n_actual < n_target:
+                data_ax = int(mesh.shape["data"])
+                scaled = max(1, batch_size * n_actual // n_target)
+                global_batch = max(data_ax, (scaled // data_ax) * data_ax)
+            data = make_dataset(data_kind, global_batch, seq_len,
+                                cfg.vocab_size, path=data_path, seed=data_seed)
+            if prefetch_depth > 0:
+                data = PrefetchingLoader(data, depth=prefetch_depth)
 
-        def on_commit(ckpt_step: int, path: str, duration_s: float,
-                      nbytes: int) -> None:
-            # the resume contract's publish point: ONLY after the atomic
-            # rename landed (on the async path this runs on the writer
-            # thread), so the AM can never resume from an uncommitted step
-            ctx.shared["ckpt_step"] = ckpt_step
-            if ctx.events is not None:
-                ctx.events.emit(f"ckpt:{exec_id}", "ckpt_committed",
-                                step=ckpt_step, duration_s=duration_s,
-                                bytes=nbytes, attempt=attempt,
-                                is_async=ckpt_async)
+            def on_commit(ckpt_step: int, path: str, duration_s: float,
+                          nbytes: int) -> None:
+                # the resume contract's publish point: ONLY after the atomic
+                # rename landed (on the async path this runs on the writer
+                # thread), so the AM can never resume from an uncommitted step
+                ctx.shared["ckpt_step"] = ckpt_step
+                if ctx.events is not None:
+                    ctx.events.emit(f"ckpt:{exec_id}", "ckpt_committed",
+                                    step=ckpt_step, duration_s=duration_s,
+                                    bytes=nbytes, attempt=attempt,
+                                    is_async=ckpt_async)
 
-        if ckpt_async:
-            ckpt = AsyncCheckpointer(
-                ckpt_dir, on_commit=on_commit,
-                chaos_hook=lambda s: ctx.chaos.check_ckpt_write(
-                    exec_id, attempt, s))
-            # graceful teardown paths (executor exit, mid-attempt shed)
-            # drain the writer so committed work is never lost
-            ctx.register_flusher(ckpt.flush)
-        else:
-            ckpt = Checkpointer(ckpt_dir)
-        with jax.set_mesh(mesh):
-            train_fn, state_pspecs = make_train_fn(
-                cfg, mesh, strategy, opt=AdamWConfig(lr=lr, weight_decay=0.0))
-            # the state is born sharded: never gathered whole on one device
-            shardings = to_shardings(state_pspecs, mesh)
-            init = jax.jit(functools.partial(init_train_state, cfg),
-                           out_shardings=shardings)
-            rng = jax.random.PRNGKey(0)
-            # checkpoint-aware recovery: prefer the AM's resume_step (the
-            # deepest checkpoint a previous attempt committed), fall back to
-            # whatever this directory holds (resume across submissions), and
-            # only then cold-start from step 0
-            start = 0
-            state = None
-            template = jax.eval_shape(init, rng)
-            target = ctx.shared.get("resume_step")
-            if target is None:
-                target = ckpt.latest_step()
-            if target is not None:
-                try:
-                    state = ckpt.restore(template, int(target))
-                except (FileNotFoundError, KeyError, ValueError, OSError):
+            if ckpt_async:
+                ckpt = AsyncCheckpointer(
+                    ckpt_dir, on_commit=on_commit,
+                    chaos_hook=lambda s: ctx.chaos.check_ckpt_write(
+                        exec_id, attempt, s))
+                # graceful teardown paths (executor exit, mid-attempt shed)
+                # drain the writer so committed work is never lost
+                ctx.register_flusher(ckpt.flush)
+            else:
+                ckpt = Checkpointer(ckpt_dir)
+            with jax.set_mesh(mesh):
+                train_fn, state_pspecs = make_train_fn(
+                    cfg, mesh, strategy,
+                    opt=AdamWConfig(lr=lr, weight_decay=0.0))
+                # the state is born sharded: never gathered whole on one device
+                shardings = to_shardings(state_pspecs, mesh)
+                init = jax.jit(functools.partial(init_train_state, cfg),
+                               out_shardings=shardings)
+                rng = jax.random.PRNGKey(0)
+                # checkpoint-aware recovery: prefer the AM's resume_step (the
+                # deepest checkpoint a previous attempt committed), fall back
+                # to whatever this directory holds (resume across
+                # submissions), and only then cold-start from step 0
+                start = 0
+                state = None
+                template = jax.eval_shape(init, rng)
+                target = ctx.shared.get("resume_step")
+                if target is None:
                     target = ckpt.latest_step()
-                    if target is not None:
+                if target is not None:
+                    try:
                         state = ckpt.restore(template, int(target))
-            state = (init(rng) if state is None
-                     else jax.device_put(state, shardings))
+                    except (FileNotFoundError, KeyError, ValueError, OSError):
+                        target = ckpt.latest_step()
+                        if target is not None:
+                            state = ckpt.restore(template, int(target))
+                state = (init(rng) if state is None else _put_restored(
+                    state, shardings, exec_id=exec_id, attempt=attempt))
             if target is not None:
                 data.load_state_dict({"step": int(target)})
                 start = int(target)
@@ -197,36 +233,50 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                 ctx.shared.setdefault("restarts", []).append(
                     {"attempt": attempt, "restored_step": start})
 
-            losses = ctx.shared.setdefault("loss_history", [])
+        losses = ctx.shared.setdefault("loss_history", [])
+        with jax.set_mesh(mesh):
             try:
                 for step in range(start, steps):
                     if ctx.cancel.is_set():
                         return 143
-                    # records progress for straggler detection + runs the
-                    # chaos hooks (which may delay or kill this step)
-                    ctx.step(exec_id, attempt, step)
-                    if fail_at is not None and (attempt, step) == fail_at:
-                        raise RuntimeError(
-                            f"injected transient failure at attempt={attempt} step={step}")
-                    batch = {k: jnp.asarray(v)
-                             for k, v in data.next_batch().items()}
-                    state, metrics = train_fn(state, batch)
-                    loss = float(metrics["loss"])
-                    losses.append((step, loss))
-                    if on_step:
-                        on_step(step, {k: float(v) for k, v in metrics.items()})
-                    if (step + 1) % ckpt_every == 0 or step + 1 == steps:
-                        if ckpt_async:
-                            # snapshot + hand off; the writer publishes
-                            # ckpt_step after commit. A deferred writer error
-                            # (e.g. a chaos kill mid-write) re-raises here.
-                            ckpt.save(state, step + 1)
-                        else:
-                            t0 = time.monotonic()
-                            path = ckpt.save(
-                                jax.tree.map(np.asarray, state), step + 1)
-                            on_commit(step + 1, path, time.monotonic() - t0,
-                                      tree_nbytes(state))
+                    with tracing.span("train.step", exec_id=exec_id,
+                                      attempt=attempt, step=step):
+                        # records progress for straggler detection + runs
+                        # the chaos hooks (which may delay or kill this step)
+                        with tracing.span("train.chaos_hook"):
+                            ctx.step(exec_id, attempt, step)
+                        if fail_at is not None and (attempt, step) == fail_at:
+                            raise RuntimeError(
+                                "injected transient failure at "
+                                f"attempt={attempt} step={step}")
+                        with tracing.span("train.next_batch"):
+                            host_batch = data.next_batch()
+                        with tracing.span("train.h2d"):
+                            batch = {k: jnp.asarray(v)
+                                     for k, v in host_batch.items()}
+                        with tracing.span("train.dispatch"):
+                            state, metrics = train_fn(state, batch)
+                        with tracing.span("train.loss_wait"):
+                            loss = float(metrics["loss"])
+                        losses.append((step, loss))
+                        if on_step:
+                            with tracing.span("train.on_step"):
+                                on_step(step, {k: float(v)
+                                               for k, v in metrics.items()})
+                        if (step + 1) % ckpt_every == 0 or step + 1 == steps:
+                            with tracing.span("ckpt.save"):
+                                if ckpt_async:
+                                    # snapshot + hand off; the writer
+                                    # publishes ckpt_step after commit. A
+                                    # deferred writer error (e.g. a chaos
+                                    # kill mid-write) re-raises here.
+                                    ckpt.save(state, step + 1)
+                                else:
+                                    t0 = time.monotonic()
+                                    path = ckpt.save(state, step + 1)
+                                    on_commit(step + 1, path,
+                                              time.monotonic() - t0,
+                                              tree_nbytes(state))
                 if ckpt_async:
                     # normal exit: surface any deferred writer error and make
                     # sure the final checkpoint committed before succeeding
@@ -236,16 +286,24 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                     ckpt.close()
                 if prefetch_depth > 0:
                     data.close()
-            ctx.shared[f"metrics:{exec_id}"] = {
-                "peak_memory_mb": float(
-                    sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
-                    / 1e6),
-                "steps": float(steps),
-                "final_loss": losses[-1][1] if losses else float("nan"),
-                "train_seconds": time.monotonic() - t_start,
-                "world_size": float(sum(counts.values())),
-                "global_batch": float(global_batch),
-            }
+        report = {
+            "steps": float(steps),
+            "final_loss": losses[-1][1] if losses else float("nan"),
+            "world_size": float(sum(counts.values())),
+            "global_batch": float(global_batch),
+        }
+        stats = [d.memory_stats() for d in mesh.devices.flat]
+        if all(st and "peak_bytes_in_use" in st for st in stats):
+            report["peak_memory_mb"] = float(
+                max(st["peak_bytes_in_use"] for st in stats) / 1e6)
+        # seconds per span name over this attempt: where the chief's time
+        # went (nested spans are counted inside their parents too)
+        for sp in tracing.spans(since=t_entry):
+            if (sp.attrs.get("exec_id") == exec_id
+                    and sp.attrs.get("attempt") == attempt):
+                key = f"span_s:{sp.name}"
+                report[key] = report.get(key, 0.0) + sp.duration
+        ctx.shared[f"metrics:{exec_id}"] = report
         return 0
 
     return program

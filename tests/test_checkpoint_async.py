@@ -40,19 +40,19 @@ def test_second_save_blocks_until_writer_commits(tmp_path, monkeypatch):
     gate = threading.Event()
     in_writer = threading.Event()
     active = []
-    real = ck.save_pytree
+    real = ck._write
 
-    def gated(tree, directory, step, pre_commit=None):
+    def gated(flat, directory, step, pre_commit=None):
         active.append(step)
         assert len(active) == 1, "two writes in flight (depth > 1)"
         in_writer.set()
         gate.wait(5)
         try:
-            return real(tree, directory, step, pre_commit=pre_commit)
+            return real(flat, directory, step, pre_commit=pre_commit)
         finally:
             active.remove(step)
 
-    monkeypatch.setattr(ck, "save_pytree", gated)
+    monkeypatch.setattr(ck, "_write", gated)
     c = AsyncCheckpointer(str(tmp_path))
     try:
         t0 = time.monotonic()
