@@ -458,10 +458,11 @@ class _ModuleProxy:
 def test_checkpoint_kill_points_never_expose_uncommitted_step(tmp_path,
                                                               monkeypatch):
     """Deterministic twin of the hypothesis property (test_property.py):
-    hard-kill the checkpoint writer at each op inside save_pytree — during
-    the array write, during the COMMIT-marker write, and at the atomic
-    rename — leaving its debris behind (a real SIGKILL runs no finally);
-    latest_step/restore must never observe the uncommitted step."""
+    hard-kill the checkpoint writer at each op inside save_pytree — inside
+    a leaf file's write, between two leaf files, during the COMMIT-marker
+    write, and at the atomic rename — leaving its debris behind (a real
+    SIGKILL runs no finally); latest_step/restore must never observe the
+    uncommitted step."""
     import json
     import shutil
 
@@ -470,21 +471,37 @@ def test_checkpoint_kill_points_never_expose_uncommitted_step(tmp_path,
     import repro.checkpoint.checkpointer as ck
     from repro.core import ChaosKill
 
-    tree1 = {"w": np.ones((2, 2), np.float32)}
-    tree2 = {"w": np.full((2, 2), 7.0, np.float32)}
+    tree1 = {"b": np.arange(3, dtype=np.float32),
+             "w": np.ones((2, 2), np.float32)}
+    tree2 = {"b": np.full((3,), 5.0, np.float32),
+             "w": np.full((2, 2), 7.0, np.float32)}
+    real_write_leaf = ck._write_leaf
 
     def killer(*a, **k):
         raise ChaosKill("chaos: checkpoint writer killed mid-op")
 
+    def torn_leaf(path, arr):              # half the bytes land, then death
+        with open(path, "wb") as f:
+            f.write(ck._byte_view(np.ascontiguousarray(arr))[:arr.nbytes // 2])
+        killer()
+
+    def second_leaf(path, arr):            # the first leaf lands whole
+        if os.path.basename(path) != "leaf_0.bin":
+            killer()
+        real_write_leaf(path, arr)
+
     kill_points = {
-        "during_array_write": ("np", np, {"savez": killer}),
-        "during_commit_write": ("json", json, {"dump": killer}),
-        "at_atomic_rename": ("os", os, {"replace": killer}),
+        "during_array_write": (ck, "_write_leaf", torn_leaf),
+        "between_leaf_files": (ck, "_write_leaf", second_leaf),
+        "during_commit_write": (ck, "json", _ModuleProxy(json, dump=killer)),
+        "at_atomic_rename": (ck, "os", _ModuleProxy(os, replace=killer)),
     }
-    for label, (attr, mod, over) in kill_points.items():
+    template = {"b": np.zeros((3,), np.float32),
+                "w": np.zeros((2, 2), np.float32)}
+    for label, (mod, attr, patched) in kill_points.items():
         d = str(tmp_path / label)
         ck.save_pytree(tree1, d, 1)            # committed baseline
-        monkeypatch.setattr(ck, attr, _ModuleProxy(mod, **over))
+        monkeypatch.setattr(mod, attr, patched)
         # a hard kill runs no cleanup: keep the staging debris on disk
         monkeypatch.setattr(ck, "shutil",
                             _ModuleProxy(shutil, rmtree=lambda *a, **k: None))
@@ -494,8 +511,9 @@ def test_checkpoint_kill_points_never_expose_uncommitted_step(tmp_path,
         # debris may exist, but the committed view is untouched
         assert ck.latest_step(d) == 1, label
         assert not ck.is_committed(d, 2), label
-        back = ck.restore_pytree({"w": np.zeros((2, 2), np.float32)}, d)
-        np.testing.assert_array_equal(back["w"], tree1["w"])
+        back = ck.restore_pytree(template, d)
+        for key in tree1:
+            np.testing.assert_array_equal(back[key], tree1[key])
     # a marker-less step dir (manual copy, interrupted writer) is equally
     # invisible to latest_step and restore
     d = str(tmp_path / "during_array_write")

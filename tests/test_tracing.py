@@ -1,9 +1,11 @@
 """The span API (repro.core.tracing) and the spans a training job emits."""
+import functools
 import sys
 import threading
 import time
 
 import jax
+import numpy as np
 import pytest
 
 from repro.core import (EventLog, FaultInjector, FaultKind, FaultPlan,
@@ -127,7 +129,9 @@ def test_kill_job_emits_each_attempts_spans_in_order(tmp_path):
     step-4 checkpoint: each attempt's chief tiles its timeline with
     task.rendezvous, chief.build and one train.step per step, and only the
     relaunched attempt restores."""
+    from repro.checkpoint import tree_nbytes
     from repro.configs import get_config
+    from repro.distributed.steps import init_train_state
     from repro.launch.programs import make_train_program
 
     steps, every, kill = 10, 4, 6
@@ -140,8 +144,9 @@ def test_kill_job_emits_each_attempts_spans_in_order(tmp_path):
                                "tony.application.max-attempts": "2",
                                "tony.worker.instances": "2",
                                "tony.worker.memory": "1024"})
+    cfg = get_config("tony-paper-mlp").replace(**CFG_KW)
     prog = make_train_program(
-        get_config("tony-paper-mlp").replace(**CFG_KW), steps=steps,
+        cfg, steps=steps,
         batch_size=4, seq_len=16, ckpt_dir=str(tmp_path / "ck"),
         ckpt_every=every, on_step=lambda s, m: None)
     t0 = time.monotonic()
@@ -176,6 +181,14 @@ def test_kill_job_emits_each_attempts_spans_in_order(tmp_path):
             [put] = puts
             assert put.thread == "ckpt-restore-put"
             assert build.start <= put.start <= build.end
+            # the whole state, one raw file per leaf
+            [read] = kids[build.span_id]
+            state = jax.eval_shape(functools.partial(init_train_state, cfg),
+                                   jax.random.PRNGKey(0))
+            assert read.attrs["layout"] == "leaves"
+            assert read.attrs["files"] == len(jax.tree.leaves(state))
+            assert read.attrs["bytes"] == tree_nbytes(jax.tree.map(
+                lambda x: np.empty(x.shape, x.dtype), state))
         for step_span, step in zip(top[2:], range(first, last + 1)):
             assert step_span.attrs["step"] == step
             if attempt == 1 and step == kill:       # killed in its hook
