@@ -46,6 +46,7 @@ class ModelConfig:
                                         # (+9% bytes on this XLA) — off by default
     softmax_dtype: str = "float32"      # f32 (safe) | bfloat16 (§Perf trade)
     rope_theta: float = 10000.0
+    rope_scaling: float = 1.0           # linear RoPE scaling: positions / factor
     pos_embedding: str = "rope"         # rope | learned | none
     max_position: int = 0               # learned pos table size (0 = seq dependent)
     num_media_tokens: int = 0           # vlm patch embeds / audio frames (stub frontend)

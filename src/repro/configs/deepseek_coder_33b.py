@@ -1,6 +1,8 @@
 """DeepSeek-Coder 33B — dense llama-arch code model.
 
-[arXiv:2401.14196] 62L d_model=7168 56H (GQA kv=8) d_ff=19200 vocab=32256.
+[arXiv:2401.14196] 62L d_model=7168 56H (GQA kv=8) d_ff=19200 vocab=32256,
+untied head, rope_theta 1e5 with linear RoPE scaling, factor 4, over
+16,384 positions (the published config.json).
 """
 from repro.configs.base import ModelConfig
 
@@ -17,8 +19,9 @@ CONFIG = ModelConfig(
     block_pattern=(("attn", "mlp"),),
     mlp_variant="swiglu",
     rope_theta=100_000.0,
+    rope_scaling=4.0,
     tie_embeddings=False,
     decode_window=8192,
     supports_long_context=True,
-    source="arXiv:2401.14196",
+    source="https://huggingface.co/deepseek-ai/deepseek-coder-33b-base/blob/main/config.json",
 )

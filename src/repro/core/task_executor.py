@@ -165,6 +165,10 @@ class JobContext:
 
 class TaskExecutor:
     HEARTBEAT_INTERVAL_S = 0.02
+    # how long a finished task drains its async work, heartbeating, before
+    # it reports a hung flusher as its failure (the checkpoint writer's own
+    # close timeout)
+    DRAIN_TIMEOUT_S = 30.0
 
     def __init__(self, task_type: str, index: int, container: Container,
                  am: "ApplicationMasterProtocol", ml_program: MLProgram,
@@ -270,17 +274,20 @@ class TaskExecutor:
             child_t.start()
             attempt = int(self.ctx.shared.get("attempt", 1))
             self.chaos.task_started(self.exec_id, attempt)
-            while child_t.is_alive():
+
+            def beat() -> None:
                 if self.chaos.drop_heartbeat(self.exec_id, attempt) or \
                         self.chaos.partition_active(self.exec_id, attempt):
                     # chaos: simulated network partition — the AM sees a
                     # silent task and attributes a heartbeat timeout
-                    pass
-                else:
-                    # heartbeats carry the child's latest step so the AM can
-                    # spot stragglers (core/speculation.py)
-                    self.am.heartbeat(self.exec_id,
-                                      progress=self.ctx.progress.get(self.exec_id))
+                    return
+                # heartbeats carry the child's latest step so the AM can
+                # spot stragglers (core/speculation.py)
+                self.am.heartbeat(self.exec_id,
+                                  progress=self.ctx.progress.get(self.exec_id))
+
+            while child_t.is_alive():
+                beat()
                 if self.ctx.cancel.is_set():
                     # AM-initiated teardown: abandon the child (thread stand-in
                     # for SIGKILL on the real container process)
@@ -307,10 +314,31 @@ class TaskExecutor:
             # graceful teardown: let in-flight async work (checkpoint
             # writer, prefetcher) finish committing before the exit is
             # reported — an already-staged checkpoint must still publish
-            # its ckpt_step so the next attempt resumes from it
-            self.ctx.flush_async()
+            # its ckpt_step so the next attempt resumes from it. The task
+            # keeps heartbeating while it drains: a write of a large state
+            # outlasts the AM's heartbeat timeout, and a silent drain would
+            # turn this exit into a lost-heartbeat failure
+            drain = threading.Thread(target=self.ctx.flush_async,
+                                     name=f"drain-{self.exec_id}", daemon=True)
+            drain.start()
+            deadline = time.monotonic() + self.DRAIN_TIMEOUT_S
+            while drain.is_alive() and time.monotonic() < deadline:
+                beat()
+                drain.join(self.HEARTBEAT_INTERVAL_S)
             self.exit_status = int(result.get("exit", 0))
             self.diagnostics = result.get("diag")
+            if drain.is_alive():
+                # a hung writer must not keep a finished task alive: the
+                # task fails, and says why unless the child already did
+                self.log(f"async work did not drain in {self.DRAIN_TIMEOUT_S} s")
+                self.exit_status = self.exit_status or EXIT_EXECUTOR_ERROR
+                self.diagnostics = self.diagnostics or TaskDiagnostics(
+                    task_id=self.exec_id, exit_status=self.exit_status,
+                    classification=FailureClass.INFRA,
+                    exception_type="DrainTimeout",
+                    message="in-flight async work (checkpoint writer, "
+                            "prefetcher) did not finish within "
+                            f"{self.DRAIN_TIMEOUT_S} s of the child's exit")
             self.metrics = dict(self.ctx.shared.get(f"metrics:{self.exec_id}", {}))
         except Exception as e:  # noqa: BLE001
             self.log(f"executor error: {e}")

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import json
+import math
 import queue
 import threading
 import time
@@ -46,6 +47,15 @@ def _local_mesh(strategy: str):
             break
     return jax.make_mesh((n // model, model), ("data", "model"),
                          axis_types=(AxisType.Auto, AxisType.Auto))
+
+
+def _bytes_per_device(template, shardings) -> int:
+    """Bytes of the state that one device holds: each leaf's shard, at the
+    largest shard shape where a split is uneven."""
+    sizes = jax.tree.map(
+        lambda leaf, sh: math.prod(sh.shard_shape(leaf.shape))
+        * leaf.dtype.itemsize, template, shardings)
+    return int(sum(jax.tree.leaves(sizes)))
 
 
 def _put_restored(state, shardings, **attrs):
@@ -153,7 +163,8 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
         # the spans of one attempt tile the chief's timeline from entry to
         # each step: task.rendezvous, chief.build (with ckpt.restore.read
         # inside it on a resume), then one train.step each
-        with tracing.span("chief.build", exec_id=exec_id, attempt=attempt):
+        with tracing.span("chief.build", exec_id=exec_id,
+                          attempt=attempt) as build:
             mesh = _local_mesh(strategy)
             # elastic resize: shard for the gang that ACTUALLY launched, not
             # the one the config asked for. A degraded attempt scales the
@@ -232,6 +243,10 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                 ctx.shared["ckpt_step"] = start
                 ctx.shared.setdefault("restarts", []).append(
                     {"attempt": attempt, "restored_step": start})
+            # set last, so that the spans inside do not inherit them
+            build.attrs.update(
+                chips=mesh.devices.size, mesh=tuple(mesh.devices.shape),
+                state_bytes_per_device=_bytes_per_device(template, shardings))
 
         losses = ctx.shared.setdefault("loss_history", [])
         with jax.set_mesh(mesh):

@@ -93,7 +93,7 @@ def self_attention(cfg, p, x, *, causal: bool, window: int, positions=None):
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.pos_embedding == "rope":
         pos = positions if positions is not None else jnp.arange(T)
-        sin, cos = rope_angles(pos, hd, cfg.rope_theta)
+        sin, cos = rope_angles(pos, hd, cfg.rope_theta, cfg.rope_scaling)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
     q = constrain_any(q, ("batch", None, "model", None),
@@ -169,7 +169,8 @@ def decode_self_attention(cfg, p, x_t, cache, pos, *, window: int):
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.pos_embedding == "rope":
-        sin, cos = rope_angles(pos[None], hd, cfg.rope_theta)  # (1, hd/2)
+        sin, cos = rope_angles(pos[None], hd, cfg.rope_theta,
+                               cfg.rope_scaling)  # (1, hd/2)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)  # rotated at true position before caching
     slot = jnp.mod(pos, C)

@@ -170,11 +170,19 @@ def norm_spec(d: int) -> PSpec:
 # Rotary embeddings
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """positions: (...,) int -> (sin, cos) of shape (..., head_dim//2), f32."""
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                scaling: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """positions: (...,) int -> (sin, cos) of shape (..., head_dim//2), f32.
+
+    ``scaling`` is linear RoPE scaling (position interpolation): the angles
+    are those of positions / scaling. At 1.0 nothing is divided, so the
+    traced computation is that of an unscaled model."""
     half = head_dim // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[..., None] * freq
+    pos = positions.astype(jnp.float32)
+    if scaling != 1.0:
+        pos = pos / scaling
+    ang = pos[..., None] * freq
     return jnp.sin(ang), jnp.cos(ang)
 
 

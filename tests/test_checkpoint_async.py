@@ -179,3 +179,115 @@ def test_kill_during_aside_cleanup_shows_new_content(tmp_path, monkeypatch):
     # gc clears the now-redundant aside once the final dir is committed
     Checkpointer(d)._gc()
     assert not [e for e in os.listdir(d) if e.startswith(".aside-")]
+
+
+# ----------------------------------------------------------------------
+# a write still in flight when the chief dies or its attempt is torn down
+
+@pytest.mark.parametrize("event", ["kill", "teardown"])
+def test_write_in_flight_past_the_heartbeat_timeout(event, tmp_path,
+                                                    monkeypatch):
+    """The chief's background write of step ``every`` outlasts the AM's
+    heartbeat timeout, and meanwhile either the planned kill lands
+    ("kill": the training thread waits in ``close`` for the write) or the
+    attempt is torn down ("teardown": the executor drains the writer before
+    it reports). The task heartbeats all through both, so each is diagnosed
+    as what it is: the kill as ChaosKill, resumed from the committed step;
+    the teardown as exit 143, not as a lost heartbeat."""
+    from repro.configs import get_config
+    from repro.core import (EventLog, FaultInjector, FaultKind, FaultPlan,
+                            FaultSpec, TonYClient, YarnLikeBackend,
+                            job_spec_from_props, make_cluster)
+    from repro.core import appmaster
+    from repro.launch.programs import make_train_program
+
+    every, write_s = 3, 5.0
+    monkeypatch.setattr(appmaster, "HEARTBEAT_TIMEOUT_S", 2.0)
+    writing = threading.Event()
+    original = ck._write
+
+    def slow_write(flat, directory, step, pre_commit=None):
+        if step == every:
+            writing.set()
+            time.sleep(write_s)
+        return original(flat, directory, step, pre_commit=pre_commit)
+
+    monkeypatch.setattr(ck, "_write", slow_write)
+    plan = FaultPlan(seed=0)
+    if event == "kill":
+        plan = plan.add(FaultSpec(FaultKind.KILL_TASK, task="worker:0",
+                                  attempt=1, at_step=every))
+    events = EventLog()
+    rm = make_cluster(event_log=events,
+                      chaos=FaultInjector(plan, events=events))
+    job = job_spec_from_props({
+        "tony.application.name": f"write-in-flight-{event}",
+        "tony.application.max-attempts": "2" if event == "kill" else "1",
+        "tony.worker.instances": "1", "tony.worker.memory": "1024"})
+    cfg = get_config("tony-paper-mlp").replace(
+        num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, head_dim=32,
+        d_ff=128, vocab_size=128, max_position=64)
+    prog = make_train_program(
+        cfg, steps=2 * every if event == "kill" else 10**6, batch_size=4,
+        seq_len=16, ckpt_dir=str(tmp_path / "ck"), ckpt_every=every)
+    chief = []
+
+    def program(env, ctx):
+        if env["TASK_INDEX"] == "0":
+            chief.append(ctx)
+        return prog(env, ctx)
+
+    if event == "teardown":
+        def tear_down():
+            if writing.wait(120):
+                chief[-1].cancel.set()
+
+        threading.Thread(target=tear_down, daemon=True).start()
+    res = TonYClient(YarnLikeBackend(rm)).run_and_wait(job, program,
+                                                       timeout=300)
+    assert writing.is_set()
+    described = {k: d.describe() for k, d in res.diagnostics.items()}
+    assert not any("HeartbeatTimeout" in d for d in described.values())
+    assert latest_step(str(tmp_path / "ck")) >= every
+    if event == "kill":
+        assert res.succeeded and res.resumed_attempts == {2: every}
+        assert list(described) == ["a1/worker:0"]
+        assert "ChaosKill" in described["a1/worker:0"]
+    else:
+        assert not res.succeeded
+        assert list(described) == ["a1/worker:0"]
+        assert "exit status 143" in described["a1/worker:0"]
+
+
+def test_hung_writer_fails_the_task_after_the_drain_deadline(monkeypatch):
+    """A flusher that never returns: the task heartbeats through the drain
+    for ``DRAIN_TIMEOUT_S`` and then reports the hang as its own failure,
+    so the job ends instead of waiting on the writer for ever."""
+    from repro.core import (EventLog, TonYClient, YarnLikeBackend,
+                            job_spec_from_props, make_cluster)
+    from repro.core import appmaster
+    from repro.core.task_executor import TaskExecutor
+
+    monkeypatch.setattr(appmaster, "HEARTBEAT_TIMEOUT_S", 0.5)
+    monkeypatch.setattr(TaskExecutor, "DRAIN_TIMEOUT_S", 2.0)
+    stuck = threading.Event()
+
+    def program(env, ctx):
+        ctx.register_flusher(stuck.wait)
+        return 0
+
+    job = job_spec_from_props({
+        "tony.application.name": "hung-writer",
+        "tony.application.max-attempts": "1",
+        "tony.worker.instances": "1", "tony.worker.memory": "1024"})
+    t0 = time.monotonic()
+    try:
+        res = TonYClient(YarnLikeBackend(make_cluster(
+            event_log=EventLog()))).run_and_wait(job, program, timeout=60)
+    finally:
+        stuck.set()
+    assert time.monotonic() - t0 < 30
+    assert not res.succeeded
+    [(task, diag)] = res.diagnostics.items()
+    assert task == "a1/worker:0"
+    assert "DrainTimeout" in diag.describe(), diag.describe()

@@ -146,3 +146,41 @@ def test_scan_vs_unrolled_layers_identical(rng):
     l_scan, _ = M.forward(cfg, params, batch)
     l_unroll, _ = M.forward(cfg.replace(scan_layers=False), params, batch)
     assert jnp.max(jnp.abs(l_scan - l_unroll)) < 1e-5
+
+
+def _unscaled_angles(positions, head_dim, theta):
+    """rope_angles as it was written before linear scaling."""
+    half = head_dim // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * freq
+    return jnp.sin(ang), jnp.cos(ang)
+
+
+@pytest.mark.parametrize("positions", [jnp.arange(4096),
+                                       jnp.array([[0, 7], [4095, 16383]])])
+def test_rope_angles_at_factor_one_trace_the_unscaled_computation(positions):
+    """Factor 1.0 divides nothing: the same jaxpr as the unscaled formula,
+    so an unscaled model compiles the program it compiled before, and the
+    same bits."""
+    from repro.models.layers import rope_angles
+
+    want = jax.make_jaxpr(_unscaled_angles, static_argnums=(1, 2))(
+        positions, 128, 1e6)
+    for got in (jax.make_jaxpr(rope_angles, static_argnums=(1, 2))(
+                    positions, 128, 1e6),
+                jax.make_jaxpr(rope_angles, static_argnums=(1, 2, 3))(
+                    positions, 128, 1e6, 1.0)):
+        assert str(got) == str(want)
+    for a, b in zip(rope_angles(positions, 128, 1e6),
+                    _unscaled_angles(positions, 128, 1e6)):
+        assert jnp.array_equal(a, b)
+
+
+def test_rope_angles_with_linear_scaling_are_at_positions_over_factor():
+    from repro.models.layers import rope_angles
+
+    pos = jnp.arange(16384)
+    scaled = rope_angles(pos, 128, 1e5, 4.0)
+    for a, b in zip(scaled, _unscaled_angles(pos / 4.0, 128, 1e5)):
+        assert jnp.array_equal(a, b)
+    assert not jnp.allclose(scaled[0], rope_angles(pos, 128, 1e5)[0])

@@ -213,3 +213,66 @@ def test_kill_job_emits_each_attempts_spans_in_order(tmp_path):
                  and s.attrs["attempt"] == 2)
     assert report["span_s:train.step"] == pytest.approx(step_s)
     assert report["span_s:ckpt.restore.read"] > 0
+
+
+FOUR_DEVICE_JOB = r"""
+import functools, json
+import jax
+from repro.configs import get_config
+from repro.core import (TonYClient, YarnLikeBackend, job_spec_from_props,
+                        make_cluster, tracing)
+from repro.distributed.sharding import to_shardings
+from repro.distributed.steps import init_train_state, make_train_fn
+from repro.launch.programs import _local_mesh, make_train_program
+
+cfg = get_config("deepseek-coder-33b").replace(
+    num_layers=2, d_model=64, num_heads=8, num_kv_heads=4, head_dim=16,
+    d_ff=128, vocab_size=256)
+job = job_spec_from_props({"tony.application.name": "four",
+                           "tony.application.max-attempts": "1",
+                           "tony.worker.instances": "4",
+                           "tony.worker.memory": "1024"})
+prog = make_train_program(cfg, steps=2, batch_size=2, seq_len=16,
+                          ckpt_dir=CKPT, ckpt_every=10**9)
+res = TonYClient(YarnLikeBackend(make_cluster())).run_and_wait(
+    job, prog, timeout=300)
+[build] = tracing.spans(prefix="chief.build")
+# what device 0 holds of the state, read from the arrays' own shards
+mesh = _local_mesh("fsdp_tp")
+with jax.set_mesh(mesh):
+    _, pspecs = make_train_fn(cfg, mesh)
+    state = jax.jit(functools.partial(init_train_state, cfg),
+                    out_shardings=to_shardings(pspecs, mesh))(
+        jax.random.PRNGKey(0))
+d0 = mesh.devices.flat[0]
+held = sum(s.data.nbytes for leaf in jax.tree.leaves(state)
+           for s in leaf.addressable_shards if s.device == d0)
+total = sum(leaf.nbytes for leaf in jax.tree.leaves(state))
+print(json.dumps({"ok": res.succeeded, "attrs": build.attrs, "held": held,
+                  "total": total}))
+"""
+
+
+def test_chief_build_names_the_mesh_and_the_state_per_device(tmp_path):
+    """A tiny four-worker job on four CPU devices, in a process of its own
+    (this one holds a one-device backend): ``chief.build`` carries the
+    chips, the (data, model) mesh and the bytes of the state that one
+    device holds."""
+    import json
+    import os
+    import subprocess
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = f"CKPT = {str(tmp_path / 'ck')!r}\n" + FOUR_DEVICE_JOB
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got["ok"]
+    attrs = got["attrs"]
+    assert attrs["chips"] == 4 and attrs["mesh"] == [1, 4]
+    assert attrs["state_bytes_per_device"] == got["held"]
+    assert got["total"] / 4 <= got["held"] < got["total"]
