@@ -1,10 +1,13 @@
 """The on-disk layout of a committed step: one raw file per leaf with a
-manifest in the COMMIT marker, read in byte ranges on a thread pool into
-arrays allocated from the template; older npz layouts still restore."""
+manifest in the COMMIT marker, restored by mapping each file copy-on-write;
+older npz layouts still restore."""
 import json
 import os
-import threading
+import shutil
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import jax
 import ml_dtypes
@@ -72,40 +75,6 @@ def test_a_non_contiguous_leaf_is_written_in_logical_order(tmp_path):
         np.testing.assert_array_equal(back[key], tree[key])
 
 
-def test_a_large_leaf_is_read_in_ranges_across_threads(tmp_path, monkeypatch):
-    """With the chunk cut to 4 KiB, a 100,012-byte leaf is written piece by
-    piece and read as 25 ranges, the last one short, at least two of them
-    at once."""
-    tree = {"big": _leaf((25_000 + 3,), np.float32), "small": np.ones(3)}
-    monkeypatch.setattr(ck, "CHUNK_BYTES", 4096)
-    monkeypatch.setattr(ck, "READ_THREADS", 4)
-    path = save_pytree(tree, str(tmp_path), 1)
-    with open(os.path.join(path, "leaf_0.bin"), "rb") as f:
-        assert f.read() == _bits(tree["big"])
-    real = ck._read_range
-    both = threading.Barrier(2, timeout=10)
-    jobs = []
-
-    def read_range(job):
-        jobs.append((os.path.basename(job[0]), job[1], job[2].nbytes,
-                     threading.current_thread().name))
-        if len(jobs) <= 2:
-            both.wait()               # two ranges in flight at once
-        real(job)
-
-    monkeypatch.setattr(ck, "_read_range", read_range)
-    back = restore_pytree(jax.tree.map(np.zeros_like, tree), str(tmp_path), 1)
-    assert _bits(back["big"]) == _bits(tree["big"])
-    np.testing.assert_array_equal(back["small"], tree["small"])
-    big = sorted((at, n) for name, at, n, _ in jobs if name == "leaf_0.bin")
-    nbytes = tree["big"].nbytes
-    assert big == [(at, min(4096, nbytes - at))
-                   for at in range(0, nbytes, 4096)]
-    assert len(big) == 25 and big[-1] == (24 * 4096, 1708)
-    assert len({thread for *_, thread in jobs}) >= 2
-    assert all(t.startswith("ckpt-read") for *_, t in jobs)
-
-
 @pytest.mark.parametrize("template, error, match", [
     ({"a": np.zeros((3, 2), np.float32)}, ValueError, "shape mismatch"),
     ({"a": np.zeros((2, 3), np.float64)}, ValueError, "dtype mismatch"),
@@ -144,21 +113,65 @@ def test_a_damaged_leaf_file_raises_oserror(tmp_path, damage):
         restore_pytree(tree, str(tmp_path), 1)
 
 
-def test_a_leaf_file_cut_short_during_the_read_raises_oserror(tmp_path,
-                                                             monkeypatch):
-    tree = {"a": np.ones((4096,), np.float32)}
+def test_a_leaf_that_cannot_be_mapped_raises_oserror_naming_its_file(
+        tmp_path):
+    """The size check comes first; a file cut short after it fails the
+    mapping, which raises ``OSError`` and names the file."""
+    path = save_pytree({"a": np.ones((64,), np.float32)}, str(tmp_path), 1)
+    leaf = os.path.join(path, "leaf_0.bin")
+    with open(leaf, "r+b") as f:
+        f.truncate(100)
+    with pytest.raises(OSError, match="cannot map .*leaf_0.bin"):
+        ck._map_leaf(leaf, 256, (64,), np.dtype(np.float32))
+
+
+def test_a_restored_leaf_written_to_leaves_its_file_unchanged(tmp_path):
+    tree = {"a": _leaf((16, 4), np.float32), "b": _leaf((3,), np.int32)}
     path = save_pytree(tree, str(tmp_path), 1)
-    monkeypatch.setattr(ck, "CHUNK_BYTES", 4096)
-    real = ck._read_range
+    back = restore_pytree(tree, str(tmp_path), 1)
+    assert all(type(x) is np.ndarray and x.flags.writeable
+               for x in jax.tree.leaves(back))
+    back["a"][...] = 0
+    back["b"] += 1
+    assert not back["a"].any()
+    for entry, leaf in (("leaf_0.bin", tree["a"]), ("leaf_1.bin", tree["b"])):
+        with open(os.path.join(path, entry), "rb") as f:
+            assert f.read() == _bits(leaf)
+    again = restore_pytree(tree, str(tmp_path), 1)
+    for key in tree:
+        assert _bits(again[key]) == _bits(tree[key])
 
-    def truncating(job):
-        with open(os.path.join(path, "leaf_0.bin"), "r+b") as f:
-            f.truncate(6000)
-        real(job)
 
-    monkeypatch.setattr(ck, "_read_range", truncating)
-    with pytest.raises(OSError, match="ends at byte"):
-        restore_pytree(tree, str(tmp_path), 1)
+@pytest.mark.parametrize("removal", ["rmtree", "gc"])
+def test_restored_leaves_outlive_their_step_directory(tmp_path, removal):
+    """A mapping stays readable, bit for bit, after its step is removed by
+    hand or by the checkpointer's garbage collection."""
+    tree = {"w": _leaf((128, 32), ml_dtypes.bfloat16),
+            "m": _leaf((5,), np.uint32)}
+    ckpt = Checkpointer(str(tmp_path), keep=1)
+    path = ckpt.save(tree, 1)
+    back = ckpt.restore(tree, 1)
+    if removal == "rmtree":
+        shutil.rmtree(path)
+    else:
+        ckpt.save(jax.tree.map(np.zeros_like, tree), 2)
+        assert ckpt.latest_step() == 2
+    assert not os.path.exists(path)
+    for key in tree:
+        assert _bits(back[key]) == _bits(tree[key])
+
+
+@pytest.mark.parametrize("shape", [(0, 3), (0,)])
+def test_a_zero_size_leaf_restores(tmp_path, shape):
+    tree = {"empty": np.zeros(shape, np.float32), "x": _leaf((4,), np.float32)}
+    path = save_pytree(tree, str(tmp_path), 1)
+    assert os.path.getsize(os.path.join(path, "leaf_0.bin")) == 0
+    t0 = time.monotonic()
+    back = restore_pytree(tree, str(tmp_path), 1)
+    assert back["empty"].shape == shape and back["empty"].dtype == np.float32
+    assert _bits(back["x"]) == _bits(tree["x"])
+    assert _read_span(t0) == {"layout": "leaves", "files": 2, "bytes": 16,
+                              "mapped": 16}
 
 
 def _read_span(t0):
@@ -180,11 +193,13 @@ def test_a_step_directory_holding_an_npz_still_restores(tmp_path):
     back = ckpt.restore(tree)
     np.testing.assert_array_equal(back["a"], tree["a"])
     assert back["b"]["c"] == 3
-    assert _read_span(t0) == {"layout": "npz", "files": 1, "bytes": 20}
+    assert _read_span(t0) == {"layout": "npz", "files": 1, "bytes": 20,
+                              "mapped": 0}
     ckpt.save(tree, 6)                                 # leaves on top
     t0 = time.monotonic()
     ckpt.restore(tree)
-    assert _read_span(t0) == {"layout": "leaves", "files": 2, "bytes": 20}
+    assert _read_span(t0) == {"layout": "leaves", "files": 2, "bytes": 20,
+                              "mapped": 20}
 
 
 def test_a_flat_npz_still_restores_and_reports_its_layout(tmp_path):
@@ -193,4 +208,93 @@ def test_a_flat_npz_still_restores_and_reports_its_layout(tmp_path):
     t0 = time.monotonic()
     back = restore_pytree(tree, str(tmp_path))
     np.testing.assert_array_equal(back["a"], tree["a"] + 1)
-    assert _read_span(t0) == {"layout": "npz", "files": 1, "bytes": 24}
+    assert _read_span(t0) == {"layout": "npz", "files": 1, "bytes": 24,
+                              "mapped": 0}
+
+
+@pytest.mark.parametrize("layout", ["leaves", "step_npz", "flat_npz"])
+def test_the_read_span_counts_the_mapped_bytes(tmp_path, layout):
+    """``mapped`` is the state's bytes on the leaf layout, 0 on either npz
+    layout; ``bytes`` is the state's bytes on all three."""
+    tree = {"a": _leaf((8, 8), np.float32), "b": _leaf((6,), np.int32)}
+    if layout == "leaves":
+        save_pytree(tree, str(tmp_path), 3)
+    elif layout == "step_npz":
+        step = tmp_path / "step_00000003"
+        step.mkdir()
+        np.savez(step / "arrays.npz", **tree)
+        (step / "COMMIT").write_text(json.dumps({"step": 3, "arrays": 2}))
+    else:
+        np.savez(str(tmp_path / "ckpt_00000003.npz"), **tree)
+    t0 = time.monotonic()
+    back = restore_pytree(tree, str(tmp_path), 3)
+    for key in tree:
+        assert _bits(back[key]) == _bits(tree[key])
+    attrs = _read_span(t0)
+    assert attrs["bytes"] == 280
+    assert attrs["mapped"] == (280 if layout == "leaves" else 0)
+
+
+PUT_JOB = r"""
+import functools, json, shutil, threading
+import jax
+import numpy as np
+from repro.checkpoint import restore_pytree, save_pytree
+from repro.configs import get_config
+from repro.distributed.sharding import to_shardings
+from repro.distributed.steps import init_train_state, make_train_fn
+from repro.launch.programs import _local_mesh, _put_restored
+
+cfg = get_config("qwen3-1.7b").replace(
+    num_layers=2, d_model=64, num_heads=8, num_kv_heads=4, head_dim=16,
+    d_ff=128, vocab_size=256)
+mesh = _local_mesh("fsdp_tp")
+with jax.set_mesh(mesh):
+    _, pspecs = make_train_fn(cfg, mesh)
+    shardings = to_shardings(pspecs, mesh)
+    init = jax.jit(functools.partial(init_train_state, cfg),
+                   out_shardings=shardings)
+    saved = init(jax.random.PRNGKey(0))
+    path = save_pytree(saved, CKPT, 4)
+    saved = jax.tree.map(np.asarray, saved)
+    host = restore_pytree(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                          CKPT, 4)
+    started = []                   # the watcher may end before we look
+    start = threading.Thread.start
+    threading.Thread.start = lambda t: (started.append(t), start(t))[1]
+    state = _put_restored(host, shardings)
+    threading.Thread.start = start
+    del host
+    [watcher] = [t for t in started if t.name == "ckpt-restore-put"]
+    watcher.join(60)
+    shutil.rmtree(path)
+    equal = [np.asarray(s.data).tobytes() == want[s.index].tobytes()
+             for leaf, want in zip(jax.tree.leaves(state),
+                                   jax.tree.leaves(saved))
+             for s in leaf.addressable_shards]
+print(json.dumps({"mesh": list(mesh.devices.shape),
+                  "watcher_alive": watcher.is_alive(), "shards": len(equal),
+                  "equal": all(equal), "leaves": len(jax.tree.leaves(state)),
+                  "sharded": sum(
+                      len({s.index for s in leaf.addressable_shards}) > 1
+                      for leaf in jax.tree.leaves(state))}))
+"""
+
+
+def test_the_put_of_a_mapped_restore_lands_bit_equal_on_every_shard(tmp_path):
+    """On four CPU devices, in a process of its own (this one holds a
+    one-device backend): ``_put_restored`` of a mapped restore onto the
+    ``(1, 4)`` mesh's shardings lands the saved state, bit for bit, on every
+    shard, and the step's files can be deleted once the put's watcher thread
+    has ended."""
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = f"CKPT = {str(tmp_path / 'ck')!r}\n" + PUT_JOB
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got["mesh"] == [1, 4] and not got["watcher_alive"]
+    assert got["shards"] == 4 * got["leaves"] and got["equal"]
+    assert got["sharded"] > 0

@@ -189,6 +189,7 @@ def test_kill_job_emits_each_attempts_spans_in_order(tmp_path):
             assert read.attrs["files"] == len(jax.tree.leaves(state))
             assert read.attrs["bytes"] == tree_nbytes(jax.tree.map(
                 lambda x: np.empty(x.shape, x.dtype), state))
+            assert read.attrs["mapped"] == read.attrs["bytes"]
         for step_span, step in zip(top[2:], range(first, last + 1)):
             assert step_span.attrs["step"] == step
             if attempt == 1 and step == kill:       # killed in its hook
