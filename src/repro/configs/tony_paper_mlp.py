@@ -3,7 +3,7 @@
 (TensorFlow-on-YARN era).  We keep it as a small dense transformer so the
 same substrate serves it; what makes it "paper-native" is the *job shape*
 (worker/ps heterogeneous containers, PS distribution strategy), exercised by
-examples/quickstart.py and the orchestration benchmarks.
+examples/quickstart.py and the end-to-end tests in tests/test_system.py.
 """
 from repro.configs.base import ModelConfig
 

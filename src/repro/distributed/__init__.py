@@ -2,10 +2,5 @@ from repro.distributed.sharding import (  # noqa: F401
     STRATEGIES,
     batch_pspecs,
     param_pspecs,
-    state_pspecs,
 )
-from repro.distributed.steps import (  # noqa: F401
-    make_decode_fn,
-    make_prefill_fn,
-    make_train_fn,
-)
+from repro.distributed.steps import make_train_fn  # noqa: F401

@@ -141,82 +141,6 @@ def batch_pspecs(mesh: Mesh, global_batch: int, strategy: str = "fsdp_tp",
     return P(axes if axes else None)
 
 
-def data_pspec(mesh: Mesh, global_batch: int, extra_dims: int,
-               strategy: str = "fsdp_tp") -> P:
-    b = batch_pspecs(mesh, global_batch, strategy)
-    return P(*b, *([None] * extra_dims))
-
-
-# ----------------------------------------------------------------------
-# Decode-state sharding: path-structure driven
-
-
-def state_pspecs(state_shapes, mesh: Mesh, global_batch: int,
-                 strategy: str = "fsdp_tp"):
-    """PartitionSpec tree for a decode state (built from eval_shape output).
-
-    KV caches (..., B, C, KV, hd): shard B over the batch axes when possible;
-    when B cannot shard (long-context batch=1) shard the cache sequence dim C
-    over 'data' (context parallelism).  Recurrent states shard their feature
-    dims over 'model'.
-    """
-    batch_axes, bsize = _batch_axes(mesh, global_batch)
-    b_ok = len(batch_axes) > 0
-    model = "model" if "model" in mesh.shape else None
-    data = "data" if "data" in mesh.shape else None
-
-    def spec(path, leaf):
-        name = None
-        for k in reversed(path):
-            if hasattr(k, "key"):
-                name = k.key
-                break
-        shape = leaf.shape
-        rank = len(shape)
-        lead = rank
-        pre: list = []
-
-        def bspec(bdim):
-            return batch_axes if (b_ok and bdim % bsize == 0) else None
-
-        if name in ("k", "v") and rank >= 4:
-            # (rep?, B, C, KV, hd)
-            pre = [None] * (rank - 4)
-            B, C, KV, hd = shape[-4:]
-            bs = bspec(B)
-            cs = None
-            if bs is None and data and C % mesh.shape[data] == 0:
-                cs = data
-            kvs = model if (model and KV % mesh.shape[model] == 0) else None
-            return P(*pre, bs, cs, kvs, None)
-        if name == "S" and rank >= 4:          # (rep?, B, H, K, K)
-            pre = [None] * (rank - 4)
-            B, H = shape[-4], shape[-3]
-            hs = model if (model and H % mesh.shape[model] == 0) else None
-            return P(*pre, bspec(B), hs, None, None)
-        if name == "h" and rank >= 2:          # (rep?, B, w)
-            pre = [None] * (rank - 2)
-            B, w = shape[-2:]
-            ws = model if (model and w % mesh.shape[model] == 0) else None
-            return P(*pre, bspec(B), ws)
-        if name == "conv" and rank >= 3:       # (rep?, B, cw-1, w)
-            pre = [None] * (rank - 3)
-            B, _, w = shape[-3:]
-            ws = model if (model and w % mesh.shape[model] == 0) else None
-            return P(*pre, bspec(B), None, ws)
-        if name in ("x_prev", "cmix_prev") and rank >= 2:
-            pre = [None] * (rank - 2)
-            B, d = shape[-2:]
-            ds = model if (model and d % mesh.shape[model] == 0) else None
-            return P(*pre, bspec(B), ds)
-        if rank == 0:
-            return P()
-        # fallback: shard batch-like first dim if divisible
-        return P(*([None] * rank))
-
-    return jax.tree_util.tree_map_with_path(spec, state_shapes)
-
-
 def to_shardings(tree_pspecs, mesh: Mesh):
     return jax.tree.map(lambda p: NamedSharding(mesh, p), tree_pspecs,
                         is_leaf=lambda x: isinstance(x, P))

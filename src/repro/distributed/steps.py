@@ -1,41 +1,20 @@
-"""Jitted distributed step builders: train / prefill / decode.
+"""The jitted distributed train step.
 
-Each builder returns (fn, in_shardings, out_shardings, arg_specs) so the
-launcher can either execute it (smoke scale) or ``.lower().compile()`` it
-against ShapeDtypeStructs (production dry-run).
+``make_train_fn`` returns the jitted AdamW step and the PartitionSpec tree of
+its state; ``init_train_state`` builds that state and ``abstract_train_state``
+its shapes without allocating.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed import sharding as sh
 from repro.models import model as M
 from repro.optim import AdamWConfig, adamw_init, adamw_update
-
-
-# ----------------------------------------------------------------------
-# Input specs (ShapeDtypeStruct stand-ins; no device allocation)
-
-
-def train_batch_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    B, T = shape.global_batch, shape.seq_len
-    specs = {
-        "tokens": jax.ShapeDtypeStruct((B, T), jnp.int32),
-        "labels": jax.ShapeDtypeStruct((B, T), jnp.int32),
-    }
-    if cfg.is_encoder_decoder:
-        specs["frames"] = jax.ShapeDtypeStruct(
-            (B, cfg.num_media_tokens, cfg.d_model), jnp.dtype(cfg.dtype))
-    elif cfg.uses_media:
-        specs["media"] = jax.ShapeDtypeStruct(
-            (B, cfg.num_media_tokens, cfg.d_model), jnp.dtype(cfg.dtype))
-    return specs
 
 
 def batch_shardings(cfg, shape, mesh, strategy) -> dict:
@@ -46,10 +25,6 @@ def batch_shardings(cfg, shape, mesh, strategy) -> dict:
     elif cfg.uses_media:
         out["media"] = P(*bspec, None, None)
     return out
-
-
-# ----------------------------------------------------------------------
-# Train
 
 
 def make_train_fn(cfg: ModelConfig, mesh: Mesh, strategy: str = "fsdp_tp",
@@ -95,19 +70,13 @@ def make_train_fn(cfg: ModelConfig, mesh: Mesh, strategy: str = "fsdp_tp",
         return {"params": new_params, "opt": new_opt,
                 "step": state["step"] + 1}, metrics
 
-    in_sh = (state_pspecs, None if shape is None else
-             batch_shardings(cfg, shape, mesh, strategy))
-    out_sh = (state_pspecs, P())
-
-    def to_named(t):
-        return jax.tree.map(lambda p: NamedSharding(mesh, p), t,
-                            is_leaf=lambda x: isinstance(x, P))
-
+    state_sh = sh.to_shardings(state_pspecs, mesh)
+    batch_sh = (None if shape is None else sh.to_shardings(
+        batch_shardings(cfg, shape, mesh, strategy), mesh))
     jitted = jax.jit(
         train_step,
-        in_shardings=(to_named(state_pspecs),
-                      to_named(in_sh[1]) if in_sh[1] is not None else None),
-        out_shardings=(to_named(state_pspecs), None),
+        in_shardings=(state_sh, batch_sh),
+        out_shardings=(state_sh, None),
         donate_argnums=(0,),
     )
     return jitted, state_pspecs
@@ -127,83 +96,3 @@ def abstract_train_state(cfg: ModelConfig) -> dict:
     return {"params": params, "opt": opt,
             "step": jax.ShapeDtypeStruct((), jnp.int32)}
 
-
-# ----------------------------------------------------------------------
-# Prefill (inference: full-sequence forward, next-token logits)
-
-
-def make_prefill_fn(cfg: ModelConfig, mesh: Mesh, strategy: str = "fsdp_tp",
-                    shape: ShapeConfig | None = None):
-    p_specs = sh.param_pspecs(cfg, mesh, strategy)
-
-    def prefill(params, batch):
-        logits, _ = M.forward(cfg, params, batch)
-        return logits[:, -1:, :]
-
-    def to_named(t):
-        return jax.tree.map(lambda p: NamedSharding(mesh, p), t,
-                            is_leaf=lambda x: isinstance(x, P))
-
-    bsh = None
-    if shape is not None:
-        bs = dict(batch_shardings(cfg, shape, mesh, strategy))
-        bs.pop("labels", None)
-        bsh = to_named(bs)
-    jitted = jax.jit(prefill, in_shardings=(to_named(p_specs), bsh))
-    return jitted, p_specs
-
-
-def prefill_batch_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    specs = train_batch_specs(cfg, shape)
-    specs.pop("labels")
-    return specs
-
-
-# ----------------------------------------------------------------------
-# Decode (single new token against a seq_len KV cache)
-
-
-def decode_state_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    """Abstract decode state via eval_shape (no allocation)."""
-    B, C = shape.global_batch, shape.seq_len
-    ab_params = M.abstract_params(cfg)
-    ctx = None
-    if cfg.uses_media:
-        ctx = jax.ShapeDtypeStruct((B, cfg.num_media_tokens, cfg.d_model),
-                                   jnp.dtype(cfg.dtype))
-
-    def init(params, context):
-        return M.init_decode_state(cfg, params, B, C, context=context)
-
-    return jax.eval_shape(init, ab_params, ctx)
-
-
-def make_decode_fn(cfg: ModelConfig, mesh: Mesh, strategy: str = "fsdp_tp",
-                   shape: ShapeConfig | None = None):
-    assert shape is not None
-    B, C = shape.global_batch, shape.seq_len
-    p_specs = sh.param_pspecs(cfg, mesh, strategy)
-    st_shapes = decode_state_specs(cfg, shape)
-    st_specs = sh.state_pspecs(st_shapes, mesh, B, strategy)
-
-    def step(params, state, tokens):
-        return M.decode_step(cfg, params, state, tokens, C)
-
-    def to_named(t):
-        return jax.tree.map(lambda p: NamedSharding(mesh, p), t,
-                            is_leaf=lambda x: isinstance(x, P))
-
-    tok_sh = NamedSharding(mesh, P(*sh.batch_pspecs(mesh, B, strategy), None))
-    jitted = jax.jit(
-        step,
-        in_shardings=(to_named(p_specs), to_named(st_specs), tok_sh),
-        donate_argnums=(1,),
-    )
-    return jitted, (p_specs, st_specs)
-
-
-def decode_token_specs(shape: ShapeConfig) -> jax.ShapeDtypeStruct:
-    return jax.ShapeDtypeStruct((shape.global_batch, 1), jnp.int32)
-
-
-partial = partial  # noqa

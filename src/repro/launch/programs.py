@@ -4,8 +4,8 @@
 training loop (model/optimizer/data/checkpointing from this repo) under
 whatever cluster spec the AM hands it.
 
-Single-process adaptation (DESIGN.md §2): the chief worker drives the
-jit-compiled SPMD step over the full local mesh; other tasks execute the
+Single-process adaptation: the chief worker drives the jit-compiled SPMD
+step over the full local mesh; other tasks execute the
 launch/rendezvous/heartbeat protocol and wait — in a real multi-host
 deployment every rank would call ``jax.distributed.initialize`` and drive the
 same program.
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType
 
-from repro.checkpoint import AsyncCheckpointer, Checkpointer, tree_nbytes
+from repro.checkpoint import AsyncCheckpointer
 from repro.configs.base import ModelConfig
 from repro.core import tracing
 from repro.core.cluster_spec import spec_task_counts
@@ -91,21 +91,20 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                        data_kind: str = "synthetic",
                        data_path: str | None = None,
                        data_seed: int = 0,
-                       ckpt_async: bool = True,
-                       prefetch_depth: int = 2,
-                       fail_at: tuple[int, int] | None = None,
                        on_step: Callable[[int, dict], None] | None = None):
-    """Returns an MLProgram. ``fail_at=(attempt, step)`` injects a crash in
-    the chief worker at that (attempt, step) — the fault-tolerance tests and
-    benchmarks use it to exercise the AM relaunch path.
+    """Returns an MLProgram: the chief worker trains ``steps`` steps, the
+    other tasks keep pace with it.
 
-    Steady-state steps are stall-free by default: ``ckpt_async`` hands the
-    checkpoint write to a background writer (``AsyncCheckpointer``) that
-    publishes ``ctx.shared["ckpt_step"]`` only after commit, and
-    ``prefetch_depth`` > 0 overlaps host-side batch construction with the
-    accelerator step (``PrefetchingLoader``). Both degrade to the synchronous
-    path (``ckpt_async=False`` / ``prefetch_depth=0``) with byte-identical
-    training and resume behavior."""
+    The chief saves every ``ckpt_every`` steps and at the last step through
+    an ``AsyncCheckpointer``: the save snapshots the state and a background
+    writer commits it, publishing ``ctx.shared["ckpt_step"]`` only after the
+    commit, so a relaunch never resumes from an uncommitted step. A
+    ``PrefetchingLoader`` builds the next two batches while the device runs
+    the step. On a normal exit the chief flushes the writer, so the last
+    checkpoint has committed before the task succeeds. Faults come from the
+    cluster's chaos plan through ``ctx.step`` and the writer's hook.
+    ``on_step(step, metrics)`` is called after each step's loss is on the
+    host."""
 
     def program(env: dict[str, str], ctx: JobContext) -> int:
         t_entry = time.monotonic()
@@ -182,33 +181,28 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                 data_ax = int(mesh.shape["data"])
                 scaled = max(1, batch_size * n_actual // n_target)
                 global_batch = max(data_ax, (scaled // data_ax) * data_ax)
-            data = make_dataset(data_kind, global_batch, seq_len,
-                                cfg.vocab_size, path=data_path, seed=data_seed)
-            if prefetch_depth > 0:
-                data = PrefetchingLoader(data, depth=prefetch_depth)
+            data = PrefetchingLoader(
+                make_dataset(data_kind, global_batch, seq_len, cfg.vocab_size,
+                             path=data_path, seed=data_seed), depth=2)
 
             def on_commit(ckpt_step: int, path: str, duration_s: float,
                           nbytes: int) -> None:
                 # the resume contract's publish point: ONLY after the atomic
-                # rename landed (on the async path this runs on the writer
-                # thread), so the AM can never resume from an uncommitted step
+                # rename landed (this runs on the writer thread), so the AM
+                # can never resume from an uncommitted step
                 ctx.shared["ckpt_step"] = ckpt_step
                 if ctx.events is not None:
                     ctx.events.emit(f"ckpt:{exec_id}", "ckpt_committed",
                                     step=ckpt_step, duration_s=duration_s,
-                                    bytes=nbytes, attempt=attempt,
-                                    is_async=ckpt_async)
+                                    bytes=nbytes, attempt=attempt)
 
-            if ckpt_async:
-                ckpt = AsyncCheckpointer(
-                    ckpt_dir, on_commit=on_commit,
-                    chaos_hook=lambda s: ctx.chaos.check_ckpt_write(
-                        exec_id, attempt, s))
-                # graceful teardown paths (executor exit, mid-attempt shed)
-                # drain the writer so committed work is never lost
-                ctx.register_flusher(ckpt.flush)
-            else:
-                ckpt = Checkpointer(ckpt_dir)
+            ckpt = AsyncCheckpointer(
+                ckpt_dir, on_commit=on_commit,
+                chaos_hook=lambda s: ctx.chaos.check_ckpt_write(
+                    exec_id, attempt, s))
+            # graceful teardown paths (executor exit, mid-attempt shed)
+            # drain the writer so committed work is never lost
+            ctx.register_flusher(ckpt.flush)
             with jax.set_mesh(mesh):
                 train_fn, state_pspecs = make_train_fn(
                     cfg, mesh, strategy,
@@ -260,10 +254,6 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                         # the chaos hooks (which may delay or kill this step)
                         with tracing.span("train.chaos_hook"):
                             ctx.step(exec_id, attempt, step)
-                        if fail_at is not None and (attempt, step) == fail_at:
-                            raise RuntimeError(
-                                "injected transient failure at "
-                                f"attempt={attempt} step={step}")
                         with tracing.span("train.next_batch"):
                             host_batch = data.next_batch()
                         with tracing.span("train.h2d"):
@@ -279,28 +269,17 @@ def make_train_program(cfg: ModelConfig, *, steps: int, batch_size: int,
                                 on_step(step, {k: float(v)
                                                for k, v in metrics.items()})
                         if (step + 1) % ckpt_every == 0 or step + 1 == steps:
+                            # snapshot + hand off; the writer publishes
+                            # ckpt_step after commit. A deferred writer error
+                            # (e.g. a chaos kill mid-write) re-raises here.
                             with tracing.span("ckpt.save"):
-                                if ckpt_async:
-                                    # snapshot + hand off; the writer
-                                    # publishes ckpt_step after commit. A
-                                    # deferred writer error (e.g. a chaos
-                                    # kill mid-write) re-raises here.
-                                    ckpt.save(state, step + 1)
-                                else:
-                                    t0 = time.monotonic()
-                                    path = ckpt.save(state, step + 1)
-                                    on_commit(step + 1, path,
-                                              time.monotonic() - t0,
-                                              tree_nbytes(state))
-                if ckpt_async:
-                    # normal exit: surface any deferred writer error and make
-                    # sure the final checkpoint committed before succeeding
-                    ckpt.flush()
+                                ckpt.save(state, step + 1)
+                # normal exit: surface any deferred writer error and make
+                # sure the final checkpoint committed before succeeding
+                ckpt.flush()
             finally:
-                if ckpt_async:
-                    ckpt.close()
-                if prefetch_depth > 0:
-                    data.close()
+                ckpt.close()
+                data.close()
         report = {
             "steps": float(steps),
             "final_loss": losses[-1][1] if losses else float("nan"),

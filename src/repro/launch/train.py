@@ -73,14 +73,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--strategy", default="fsdp_tp")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=10)
-    ap.add_argument("--ckpt-async", action=argparse.BooleanOptionalAction,
-                    default=True,
-                    help="write checkpoints from a background writer thread "
-                         "(publishes ckpt_step only after commit); "
-                         "--no-ckpt-async restores the blocking writer")
-    ap.add_argument("--prefetch-depth", type=int, default=2,
-                    help="batches the data pipeline builds ahead of the "
-                         "train step (0 = synchronous batch construction)")
     chaos = ap.add_argument_group(
         "chaos", "deterministic fault injection (core/chaos.py)")
     chaos.add_argument("--chaos-seed", type=int, default=1234,
@@ -187,7 +179,6 @@ def main(argv: list[str] | None = None) -> int:
     prog = make_train_program(
         cfg, steps=args.steps, batch_size=args.batch_size, seq_len=args.seq_len,
         ckpt_dir=os.path.join(ckpt_dir, "ckpt"), ckpt_every=args.ckpt_every,
-        ckpt_async=args.ckpt_async, prefetch_depth=args.prefetch_depth,
         strategy=args.strategy, lr=args.lr,
         on_step=lambda s, m: steps_log.append((s, m["loss"])))
 

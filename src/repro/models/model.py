@@ -14,8 +14,6 @@ Public API:
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
@@ -419,5 +417,3 @@ def decode_step(cfg: ModelConfig, params: dict, state: dict, tokens: jax.Array,
     new_state = {"pos": pos + 1, "layers": new_layers, "ctx_kv": state["ctx_kv"]}
     return logits, new_state
 
-
-partial = partial  # noqa

@@ -1,6 +1,6 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS here on purpose — smoke tests and
-benches must see the real single CPU device; only launch/dryrun.py forces
-512 placeholder devices (in its own process)."""
+"""Shared fixtures. NOTE: no XLA_FLAGS here on purpose — smoke tests must
+see the real single CPU device; a test that needs more devices starts its
+own process."""
 import jax
 import numpy as np
 import pytest

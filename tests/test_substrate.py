@@ -151,13 +151,13 @@ def test_training_reduces_loss(rng):
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig
     from repro.distributed.steps import init_train_state, make_train_fn
-    from repro.launch.mesh import make_local_mesh
+    from repro.launch.programs import _local_mesh
 
     cfg = get_config("tony-paper-mlp").replace(
         num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, head_dim=32,
         d_ff=128, vocab_size=128, max_position=64)
     data = SyntheticLMDataset(8, 32, cfg.vocab_size, seed=1)
-    mesh = make_local_mesh()
+    mesh = _local_mesh("fsdp_tp")
     with jax.set_mesh(mesh):
         fn, _ = make_train_fn(cfg, mesh, "fsdp_tp",
                               shape=ShapeConfig("t", 32, 8, "train"))

@@ -5,13 +5,25 @@ import importlib.util
 import os
 import subprocess
 import sys
+import threading
 from pathlib import Path
 
 import jax
 import pytest
 
+from repro.checkpoint import latest_step
 from repro.configs import get_config
-from repro.core import TonYClient, YarnLikeBackend, job_spec_from_props, make_cluster
+from repro.core import (
+    EventLog,
+    FaultInjector,
+    FaultKind,
+    FaultPlan,
+    FaultSpec,
+    TonYClient,
+    YarnLikeBackend,
+    job_spec_from_props,
+    make_cluster,
+)
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.programs import make_train_program
 
@@ -36,6 +48,17 @@ def _job(workers=2, ps=1):
     return job_spec_from_props(props)
 
 
+def _chaos_cluster(*faults):
+    """A cluster whose chaos plan fires ``faults``; its event log is
+    ``rm.events``."""
+    plan = FaultPlan(seed=1234)
+    for fault in faults:
+        plan = plan.add(fault)
+    events = EventLog()
+    return make_cluster(event_log=events,
+                        chaos=FaultInjector(plan, events=events))
+
+
 def test_e2e_training_job_succeeds_and_loss_drops(tmp_path):
     rm = make_cluster()
     client = TonYClient(YarnLikeBackend(rm))
@@ -56,13 +79,14 @@ def test_e2e_training_job_succeeds_and_loss_drops(tmp_path):
 def test_e2e_fault_tolerance_restores_from_checkpoint(tmp_path):
     """Kill the chief mid-run on attempt 1; AM relaunches; training resumes
     from the last checkpoint, not from scratch (the paper's §2.2 contract)."""
-    rm = make_cluster()
+    # crash attempt 1 at step 13 (after the step-10 ckpt)
+    rm = _chaos_cluster(FaultSpec(FaultKind.KILL_TASK, task="worker:0",
+                                  attempt=1, at_step=13))
     client = TonYClient(YarnLikeBackend(rm))
     seen_steps = []
     prog = make_train_program(
         CFG, steps=20, batch_size=8, seq_len=32,
         ckpt_dir=str(tmp_path / "ck"), ckpt_every=5,
-        fail_at=(1, 13),  # crash attempt 1 at step 13 (after the step-10 ckpt)
         on_step=lambda s, m: seen_steps.append(s))
     res = client.run_and_wait(_job(), prog, timeout=300)
     assert res.succeeded
@@ -72,8 +96,9 @@ def test_e2e_fault_tolerance_restores_from_checkpoint(tmp_path):
     # and the retry that saved the job is visible in the event log
     diag = res.diagnostics["a1/worker:0"]
     assert diag.classification.value == "TRANSIENT"
-    assert diag.exception_type == "RuntimeError"
-    assert "injected transient failure" in diag.traceback
+    assert diag.exception_type == "ChaosKill"
+    assert ("chaos: injected kill of worker:0 at attempt=1 step=13"
+            in diag.traceback)
     assert rm.events.count("retry_scheduled") == 1
     # attempt 2 resumed at 10 (the checkpoint), not 0
     restart_points = [s for i, s in enumerate(seen_steps[1:], 1)
@@ -86,17 +111,72 @@ def test_e2e_fault_tolerance_restores_from_checkpoint(tmp_path):
 
 
 def test_e2e_new_cluster_spec_each_attempt(tmp_path):
-    rm = make_cluster()
+    rm = _chaos_cluster(FaultSpec(FaultKind.KILL_TASK, task="worker:0",
+                                  attempt=1, at_step=2))
     client = TonYClient(YarnLikeBackend(rm))
     prog = make_train_program(
         CFG, steps=8, batch_size=4, seq_len=16,
-        ckpt_dir=str(tmp_path / "ck"), ckpt_every=4, fail_at=(1, 2))
+        ckpt_dir=str(tmp_path / "ck"), ckpt_every=4)
     res = client.run_and_wait(_job(workers=1, ps=1), prog, timeout=300)
     assert res.succeeded
     s1 = res.attempts[0].cluster_spec
     s2 = res.attempts[1].cluster_spec
     assert s1 is not None and s2 is not None
     assert s1 != s2  # fresh ports/containers -> new global spec (paper §2.2)
+
+
+def test_e2e_last_checkpoint_committed_when_job_returns(tmp_path):
+    """The chief flushes its checkpoint writer before it succeeds: when the
+    job returns, the final step has committed, and each save committed
+    once."""
+    rm = _chaos_cluster()
+    ckpt_dir = str(tmp_path / "ck")
+    prog = make_train_program(CFG, steps=8, batch_size=4, seq_len=16,
+                              ckpt_dir=ckpt_dir, ckpt_every=4)
+    res = TonYClient(YarnLikeBackend(rm)).run_and_wait(
+        _job(workers=1, ps=1), prog, timeout=300)
+    assert res.succeeded and len(res.attempts) == 1
+    assert latest_step(ckpt_dir) == 8
+    assert [e.payload["step"] for e in rm.events.of_kind("ckpt_committed")
+            ] == [4, 8]
+
+
+@pytest.mark.chaos
+def test_e2e_kill_inside_checkpoint_write_resumes_from_committed_step(
+        tmp_path):
+    """A kill inside the background write of step 8: the relaunch resumes
+    from step 4, the last committed step, never from 8."""
+    rm = _chaos_cluster(FaultSpec(FaultKind.KILL_TASK, task="worker:0",
+                                  at_step=8, in_ckpt_write=True))
+    seen_steps = []
+    prog = make_train_program(
+        CFG, steps=12, batch_size=4, seq_len=16,
+        ckpt_dir=str(tmp_path / "ck"), ckpt_every=4,
+        on_step=lambda s, m: seen_steps.append(s))
+    res = TonYClient(YarnLikeBackend(rm)).run_and_wait(
+        _job(workers=1, ps=1), prog, timeout=300)
+    assert res.succeeded and len(res.attempts) == 2
+    assert res.resumed_attempts == {2: 4}
+    assert rm.events.count("chaos_injected") == 1
+    restart_points = [s for i, s in enumerate(seen_steps[1:], 1)
+                      if s <= seen_steps[i - 1]]
+    assert restart_points == [4]
+    assert latest_step(str(tmp_path / "ck")) == 12
+
+
+def test_e2e_chief_leaves_no_thread_running(tmp_path):
+    """The chief closes its checkpoint writer and its prefetching loader,
+    and both ``close`` calls join their thread."""
+    before = set(threading.enumerate())
+    ckpt_dir = str(tmp_path / "ck")
+    prog = make_train_program(CFG, steps=8, batch_size=4, seq_len=16,
+                              ckpt_dir=ckpt_dir, ckpt_every=4)
+    res = TonYClient(YarnLikeBackend(make_cluster())).run_and_wait(
+        _job(workers=1, ps=1), prog, timeout=300)
+    assert res.succeeded
+    left = [t.name for t in threading.enumerate() if t not in before]
+    assert f"ckpt-writer:{ckpt_dir}" not in left, left
+    assert "prefetch-loader" not in left, left
 
 
 @pytest.mark.parametrize("faults,rc,status", [(0, 0, "SUCCEEDED"),
